@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
 
 NEG_INF = -2.3819763e38
 
@@ -114,7 +113,7 @@ def flash_prefill(q, k, v, *, causal=True, window: int = 0,
             pltpu.VMEM((block_q, hd), jnp.float32),
         ],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
     )(q, k, v)

@@ -17,7 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
 
 NEG_INF = -2.3819763e38
 
@@ -51,7 +50,7 @@ def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         m_prev, l_prev, acc_prev = m_ref[...], l_ref[...], acc_ref[...]
         s_rows = []
         for kv in range(Hkv):
-            qg = jax.lax.dynamic_slice_in_dim(q, kv * groups, groups, 0)
+            qg = q[kv * groups:(kv + 1) * groups]
             s_kv = jax.lax.dot_general(qg, k[:, kv],
                                        (((1,), (1,)), ((), ())),
                                        preferred_element_type=jnp.float32)
@@ -68,7 +67,7 @@ def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, o_ref,
         l_new = l_prev * corr + jnp.sum(pexp, axis=1)
         pv_rows = []
         for kv in range(Hkv):
-            pg = jax.lax.dynamic_slice_in_dim(pexp, kv * groups, groups, 0)
+            pg = pexp[kv * groups:(kv + 1) * groups]
             pv_kv = jax.lax.dot_general(pg, v[:, kv],
                                         (((1,), (0,)), ((), ())),
                                         preferred_element_type=jnp.float32)
@@ -128,7 +127,7 @@ def _dbuf_kernel(table_ref, lens_ref, q_ref, kp_ref, vp_ref, o_ref,
                  ) < seq_len
         s_rows = []
         for kv in range(Hkv):
-            qg = jax.lax.dynamic_slice_in_dim(q, kv * groups, groups, 0)
+            qg = q[kv * groups:(kv + 1) * groups]
             s_kv = jax.lax.dot_general(qg, k[:, kv],
                                        (((1,), (1,)), ((), ())),
                                        preferred_element_type=jnp.float32)
@@ -143,7 +142,7 @@ def _dbuf_kernel(table_ref, lens_ref, q_ref, kp_ref, vp_ref, o_ref,
         l_new = l_prev * corr + jnp.sum(pexp, axis=1)
         pv_rows = []
         for kv in range(Hkv):
-            pg = jax.lax.dynamic_slice_in_dim(pexp, kv * groups, groups, 0)
+            pg = pexp[kv * groups:(kv + 1) * groups]
             pv_rows.append(jax.lax.dot_general(
                 pg, v[:, kv], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))
@@ -172,7 +171,7 @@ def paged_decode(q, k_pages, v_pages, block_table, lens, *,
     scale = scale if scale is not None else 1.0 / math.sqrt(hd)
 
     if dbuf:
-        any_spec = pl.BlockSpec(memory_space=pltpu.ANY)
+        any_spec = pl.BlockSpec(memory_space=pl.ANY)
         return pl.pallas_call(
             functools.partial(_dbuf_kernel, page_size=page_size, groups=G,
                               scale=scale, softcap=softcap),
@@ -193,7 +192,7 @@ def paged_decode(q, k_pages, v_pages, block_table, lens, *,
             ),
             out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
             interpret=interpret,
-            compiler_params=CompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("arbitrary",)),
         )(block_table, lens, q, k_pages, v_pages)
 
@@ -221,7 +220,7 @@ def paged_decode(q, k_pages, v_pages, block_table, lens, *,
         ),
         out_shape=jax.ShapeDtypeStruct((B, H, hd), q.dtype),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
     )(block_table, lens, q, k_pages, v_pages)
     return out
@@ -269,6 +268,6 @@ def paged_insert(k_pages, v_pages, k_new, v_new, page_idx, offset, *,
                    jax.ShapeDtypeStruct(v_pages.shape, v_pages.dtype)],
         input_output_aliases={4: 0, 5: 1},
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
     )(page_idx, offset, k_new, v_new, k_pages, v_pages)

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..compat import CompilerParams
 
 
 def _kernel(x_ref, dt_ref, b_ref, c_ref, a_ref, d_ref, y_ref, hout_ref,
@@ -103,7 +102,7 @@ def ssd_scan(x, dt, A, B, C, D, *, chunk: int = 128, interpret: bool = False):
         ],
         scratch_shapes=[pltpu.VMEM((hd, N), jnp.float32)],
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
     )(x, dt, B, C, A2, D2)
     return y, h

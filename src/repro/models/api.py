@@ -8,6 +8,7 @@ decode tokens: (B,) i32.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional
 
 import jax
@@ -29,9 +30,10 @@ class Model:
     cfg: Any
 
     # ---- params ----------------------------------------------------
-    def init(self, key):
-        params, _ = split_boxes(_mod(self.cfg).init(key, self.cfg))
-        return params
+    def init(self, key, dtype=jnp.float32):
+        """Random params in `dtype`, built and cast inside one jitted
+        program: a bf16 model never holds an f32 copy on the device."""
+        return _init(self.cfg, dtype, key)
 
     def init_with_axes(self, key):
         return split_boxes(_mod(self.cfg).init(key, self.cfg))
@@ -50,9 +52,12 @@ class Model:
         return shapes, axes
 
     def cast(self, params, dtype):
+        """Floating leaves to `dtype`; leaves already in it are returned
+        as they are (no copy)."""
         return jax.tree.map(
-            lambda x: x.astype(dtype) if jnp.issubdtype(x.dtype, jnp.floating) else x,
-            params)
+            lambda x: x.astype(dtype)
+            if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != dtype
+            else x, params)
 
     # ---- compute ----------------------------------------------------
     def _fe(self, batch):
@@ -112,6 +117,12 @@ def supports_paged(cfg) -> bool:
     return (cfg.family in ("dense", "moe", "vlm")
             and cfg.sliding_window == 0
             and not cfg.local_global_ratio)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _init(cfg, dtype, key):
+    params, _ = split_boxes(_mod(cfg).init(key, cfg))
+    return Model(cfg).cast(params, dtype)
 
 
 def build_model(cfg) -> Model:

@@ -39,21 +39,21 @@ def init(key, cfg):
     return {
         "embed": param(next(keys), (cfg.vocab_size, d), ("vocab", "embed"),
                        scale=cfg.d_model ** -0.5),
-        "enc": stack_init(lambda: {
-            "ln1": norm_params(next(keys), d, cfg),
-            "attn": attn_params(keys, cfg),
-            "ln2": norm_params(next(keys), d, cfg),
-            "ffn": dense_ffn_params(keys, d, cfg.d_ff),
-        }, cfg.encoder_layers),
+        "enc": stack_init(lambda ks: {
+            "ln1": norm_params(next(ks), d, cfg),
+            "attn": attn_params(ks, cfg),
+            "ln2": norm_params(next(ks), d, cfg),
+            "ffn": dense_ffn_params(ks, d, cfg.d_ff),
+        }, cfg.encoder_layers, keys),
         "enc_norm": norm_params(next(keys), d, cfg),
-        "dec": stack_init(lambda: {
-            "ln1": norm_params(next(keys), d, cfg),
-            "attn": attn_params(keys, cfg),
-            "lnx": norm_params(next(keys), d, cfg),
-            "xattn": _xattn_params(keys, cfg),
-            "ln2": norm_params(next(keys), d, cfg),
-            "ffn": dense_ffn_params(keys, d, cfg.d_ff),
-        }, cfg.num_layers),
+        "dec": stack_init(lambda ks: {
+            "ln1": norm_params(next(ks), d, cfg),
+            "attn": attn_params(ks, cfg),
+            "lnx": norm_params(next(ks), d, cfg),
+            "xattn": _xattn_params(ks, cfg),
+            "ln2": norm_params(next(ks), d, cfg),
+            "ffn": dense_ffn_params(ks, d, cfg.d_ff),
+        }, cfg.num_layers, keys),
         "final_norm": norm_params(next(keys), d, cfg),
         "lm_head": param(next(keys), (d, cfg.vocab_size), ("embed", "vocab")),
     }

@@ -36,9 +36,9 @@ def init(key, cfg):
         "lm_head": param(next(keys), (cfg.d_model, cfg.vocab_size),
                          ("embed", "vocab")),
         "groups": _reshape_groups(
-            stack_init(lambda: {"m": mamba_params(keys, cfg),
-                                "ln": norm_params(next(keys), cfg.d_model, cfg)},
-                       n_groups * every), n_groups, every),
+            stack_init(lambda ks: {"m": mamba_params(ks, cfg),
+                                   "ln": norm_params(next(ks), cfg.d_model, cfg)},
+                       n_groups * every, keys), n_groups, every),
         "shared": {
             "ln1": norm_params(next(keys), cfg.d_model, cfg),
             "attn": attn_params(keys, cfg),
@@ -47,9 +47,9 @@ def init(key, cfg):
         },
     }
     if tail:
-        p["tail"] = stack_init(lambda: {"m": mamba_params(keys, cfg),
-                                        "ln": norm_params(next(keys), cfg.d_model, cfg)},
-                               tail)
+        p["tail"] = stack_init(lambda ks: {"m": mamba_params(ks, cfg),
+                                           "ln": norm_params(next(ks), cfg.d_model, cfg)},
+                               tail, keys)
     return p
 
 

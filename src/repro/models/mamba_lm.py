@@ -16,10 +16,10 @@ def init(key, cfg):
     return {
         "embed": param(next(keys), (cfg.vocab_size, cfg.d_model),
                        ("vocab", "embed"), scale=cfg.d_model ** -0.5),
-        "layers": stack_init(lambda: {
-            "ln": norm_params(next(keys), cfg.d_model, cfg),
-            "m": mamba_params(keys, cfg),
-        }, cfg.num_layers),
+        "layers": stack_init(lambda ks: {
+            "ln": norm_params(next(ks), cfg.d_model, cfg),
+            "m": mamba_params(ks, cfg),
+        }, cfg.num_layers, keys),
         "final_norm": norm_params(next(keys), cfg.d_model, cfg),
     }
 

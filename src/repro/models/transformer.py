@@ -68,14 +68,23 @@ def _rope_theta(kind: str, cfg) -> float:
 # params
 # ---------------------------------------------------------------------------
 
-def stack_init(fn, n: int):
-    trees = [fn() for _ in range(n)]
+def stack_init(fn, n: int, keys):
+    """`n` layers of `fn(key_iter)` stacked on a leading "layers" axis.
 
-    def merge(*boxes):
-        v = jnp.stack([b.value for b in boxes])
-        return Box(v, ("layers",) + boxes[0].axes)
+    All layers come from one vmapped program over `n` per-layer keys: a
+    jitted init then holds one RNG computation per parameter kind instead
+    of one per layer, which keeps a full-depth compile short.
+    """
+    axes = {}
 
-    return jax.tree.map(merge, *trees, is_leaf=lambda x: isinstance(x, Box))
+    def one(key):
+        vals, axes["tree"] = split_boxes(fn(keygen(key)))
+        return vals
+
+    vals = jax.vmap(one)(jax.random.split(next(keys), n))
+    return jax.tree.map(lambda ax, v: Box(v, ("layers",) + ax),
+                        axes["tree"], vals,
+                        is_leaf=lambda x: isinstance(x, tuple))
 
 
 def attn_params(keys, cfg):
@@ -121,7 +130,8 @@ def init(key, cfg):
     if not cfg.tie_embeddings:
         p["lm_head"] = param(next(keys), (d, cfg.vocab_size), ("embed", "vocab"))
     for i, seg in enumerate(layer_plan(cfg)):
-        p[f"seg{i}"] = stack_init(lambda: layer_params(keys, cfg, seg.kind), seg.n)
+        p[f"seg{i}"] = stack_init(
+            lambda ks: layer_params(ks, cfg, seg.kind), seg.n, keys)
     return p
 
 

@@ -117,17 +117,19 @@ class Sequence:
 class Engine:
     def __init__(self, cfg, params=None, *, max_batch: int = 8,
                  max_len: int = 512, seed: int = 0, attn_blocks=(128, 128),
-                 dtype=jnp.float32, page_size: int = 16,
+                 page_size: int = 16,
                  num_pages: Optional[int] = None,
                  paged: Optional[bool] = None,
                  prefix_cache: bool = False,
                  fused_prefix: Optional[bool] = None):
         self.cfg = cfg
         self.model = build_model(cfg)
-        self.dtype = dtype
         if params is None:
             params = self.model.init(jax.random.PRNGKey(seed))
-        self.params = self.model.cast(params, dtype)
+        # serve at the dtype the params arrive in (the KV cache follows);
+        # engines of one cluster share these arrays, never a copy
+        self.params = params
+        self.dtype = params["embed"].dtype
         self.max_batch = max_batch
         self.max_len = max_len
         self.attn_blocks = attn_blocks
