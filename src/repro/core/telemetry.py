@@ -45,12 +45,21 @@ into queue + prefill-compute + prefill-stall (chunk round-robin waits), the
 decode-startup path into migration + admission, and TPOT into batch-wait +
 step-compute.  `goodput.SLOTracker` attaches this to SLO violations so a
 miss comes annotated with its dominant cause.
+
+Three clocks, kept apart: the `Tracer`'s lifecycle spans run on the
+virtual clock (engine step times charged per instance), which is what the
+simulator-parity tests diff.  `host_span` marks the live program's host
+work on the profiler's clock, the one its device "XLA Ops" and "XLA
+Modules" lines use, so that a device idle gap in a `jax.profiler` trace is
+named by the program work the host was doing over it.  `Engine.stats()`
+counts the decode step's parts and the gaps between steps on the host's
+`perf_counter`.
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import json
-import math
 import re
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -58,7 +67,60 @@ __all__ = [
     "Span", "SpanEvent", "Instant", "Tracer", "NullTracer", "NULL_TRACER",
     "MetricsRegistry", "Attribution", "attribute_request",
     "to_chrome_trace", "save_chrome_trace", "validate_chrome_trace",
+    "HOST_SPANS", "host_span", "mark_gc",
 ]
+
+
+# ---------------------------------------------------------------------------
+# host spans (the profiler's clock)
+# ---------------------------------------------------------------------------
+
+# Every host span the serving program opens. They nest strictly on the
+# thread that drives the cluster: `serve.kv_handoff` inside `serve.admit`
+# or `serve.route`, `serve.prefill.sample` inside `serve.prefill`;
+# `serve.gc` lies inside whatever the thread that collects was doing.
+HOST_SPANS = (
+    "serve.route",            # arrival and prefill->decode routing, park
+    "serve.prefill",          # padding, dispatch and wait of one prefill
+    "serve.prefill.sample",   # the first token's argmax and its pull
+    "serve.admit",            # the decode side's admission loop
+    "serve.kv_handoff",       # pull, wire stitch, splice, unpin
+    "serve.decode.dispatch",  # token array and enqueue of the decode step
+    "serve.decode.wait",      # the decode step's block_until_ready
+    "serve.decode.pull",      # argmax, copy to the host, sampling
+    "serve.decode.emit",      # token callbacks, finishing, release
+    "serve.gc",               # a garbage collection (`mark_gc`)
+)
+
+
+def host_span(name: str):
+    """A span on the profiler's host plane, on the device trace's clock,
+    named from `HOST_SPANS`: a `jax.profiler.TraceAnnotation`. With no
+    profiler running it does only the profiler's activity check, so it is
+    always on."""
+    # imported here: the simulator, which shares this module, runs no JAX
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
+
+
+_gc_open: List[Any] = []
+
+
+def _gc_span(phase: str, info: Dict[str, Any]):
+    if phase == "start":
+        span = host_span("serve.gc")
+        span.__enter__()
+        _gc_open.append(span)
+    elif _gc_open:
+        _gc_open.pop().__exit__(None, None, None)
+
+
+def mark_gc():
+    """Open a `serve.gc` host span over every garbage collection in the
+    process, so that a device idle gap spent in the collector is named
+    too. Idempotent; the serving clusters call it."""
+    if _gc_span not in gc.callbacks:
+        gc.callbacks.append(_gc_span)
 
 
 # ---------------------------------------------------------------------------
@@ -75,15 +137,12 @@ class SpanEvent:
 
 @dataclasses.dataclass
 class Instant:
-    """Global instant event (token emission, routing decision, ...).
-    `wall_t` is the optional wall-clock stamp (None unless the tracer
-    carries a `wall_clock` source)."""
+    """Global instant event (token emission, routing decision, ...)."""
     name: str
     t: float
     rid: Optional[int] = None
     lane: Optional[str] = None
     args: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    wall_t: Optional[float] = None
 
 
 @dataclasses.dataclass
@@ -96,9 +155,6 @@ class Span:
     t1: Optional[float] = None
     args: Dict[str, Any] = dataclasses.field(default_factory=dict)
     events: List[SpanEvent] = dataclasses.field(default_factory=list)
-    # wall-clock stamps (opt-in; virtual t0/t1 stay the span's identity)
-    wall_t0: Optional[float] = None
-    wall_t1: Optional[float] = None
 
     @property
     def open(self) -> bool:
@@ -161,27 +217,16 @@ class Tracer:
     tokens, timings, or routing (it only filters what is *recorded*).
     Spans without a rid (decode step spans, batch-level compute) are
     instance-scoped, not request-scoped, and are always kept.
-
-    `wall_clock` (opt-in, e.g. ``time.time``) adds wall-clock stamps
-    alongside the virtual timestamps: spans gain `wall_t0`/`wall_t1`
-    (sampled at `begin`/`end` call time), instants gain `wall_t`.
-    Virtual time stays the identity — parity diffs and the phase state
-    machine never look at wall stamps — but an exported trace carries
-    both, so a live run can be lined up against real elapsed time (and
-    a sim run against search wall-cost). Default None: no stamps, no
-    per-event clock reads, byte-identical traces to before the knob.
     """
     enabled = True
 
-    def __init__(self, sample_rate: float = 1.0, sample_seed: int = 0,
-                 wall_clock: Optional[Callable[[], float]] = None):
+    def __init__(self, sample_rate: float = 1.0, sample_seed: int = 0):
         self.spans: List[Span] = []
         self.instants: List[Instant] = []
         self.terminals: Dict[int, Tuple[str, float]] = {}
         self._open_phase: Dict[int, Span] = {}
         self.sample_rate = float(sample_rate)
         self.sample_seed = int(sample_seed)
-        self.wall_clock = wall_clock
 
     def sampled(self, rid: Optional[int]) -> bool:
         """Per-request keep-all decision (deterministic rid hash)."""
@@ -199,8 +244,6 @@ class Tracer:
     def begin(self, cat: str, name: str, t: float, lane: str,
               rid: Optional[int] = None, **args) -> Span:
         sp = Span(cat, name, lane, t, rid=rid, args=args)
-        if self.wall_clock is not None:
-            sp.wall_t0 = float(self.wall_clock())
         if self.sampled(rid):
             self.spans.append(sp)
         return sp
@@ -213,9 +256,6 @@ class Tracer:
             raise ValueError(f"span ends before it starts: {span.name} "
                              f"{t} < {span.t0}")
         span.t1 = t
-        if self.wall_clock is not None:
-            span.wall_t1 = max(float(self.wall_clock()),
-                               span.wall_t0 or -math.inf)
         if args:
             span.args.update(args)
 
@@ -227,10 +267,7 @@ class Tracer:
 
     def event(self, name: str, t: float, rid: Optional[int] = None,
               lane: Optional[str] = None, **args):
-        wall = (float(self.wall_clock())
-                if self.wall_clock is not None else None)
-        self.instants.append(Instant(name, t, rid=rid, lane=lane, args=args,
-                                     wall_t=wall))
+        self.instants.append(Instant(name, t, rid=rid, lane=lane, args=args))
 
     # -- per-request phase state machine --------------------------------
     def phase(self, rid: int, name: str, t: float, lane: str, **args):
@@ -521,10 +558,6 @@ def to_chrome_trace(tracer: Tracer,
         args = {k: v for k, v in s.args.items()}
         if s.rid is not None:
             args["rid"] = s.rid
-        if s.wall_t0 is not None:
-            args["wall_t0"] = s.wall_t0
-            if s.wall_t1 is not None:
-                args["wall_t1"] = s.wall_t1
         ev = {"name": s.name, "cat": s.cat, "ph": "X", "ts": s.t0 * _US,
               "dur": (s.dur if not s.open else 0.0) * _US,
               "pid": pid_of[s.lane], "tid": 0, "args": args}
@@ -547,8 +580,6 @@ def to_chrome_trace(tracer: Tracer,
         args = dict(i.args)
         if i.rid is not None:
             args["rid"] = i.rid
-        if i.wall_t is not None:
-            args["wall_t"] = i.wall_t
         events.append({"name": i.name, "cat": "instant", "ph": "i",
                        "s": "t", "ts": i.t * _US, "pid": pid_of[lane],
                        "tid": 0, "args": args})
@@ -620,15 +651,6 @@ def validate_chrome_trace(doc: Any) -> List[str]:
             errors.append(f"{where}: non-monotone ts {ts} < {last_ts}")
         last_ts = ts
         key = (ev.get("pid"), ev.get("tid"))
-        args = ev.get("args") or {}
-        for wk in ("wall_t0", "wall_t1", "wall_t"):
-            if wk in args and not isinstance(args[wk], (int, float)):
-                errors.append(f"{where}: non-numeric {wk} {args[wk]!r}")
-        if isinstance(args.get("wall_t0"), (int, float)) and \
-                isinstance(args.get("wall_t1"), (int, float)) and \
-                args["wall_t1"] < args["wall_t0"]:
-            errors.append(f"{where}: wall_t1 {args['wall_t1']} < "
-                          f"wall_t0 {args['wall_t0']}")
         if ph == "X":
             dur = ev.get("dur")
             if not isinstance(dur, (int, float)) or dur < 0:

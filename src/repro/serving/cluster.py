@@ -47,6 +47,7 @@ import numpy as np
 from ..core.fault import HeartbeatMonitor
 from ..core.kv_transfer import TransferManager, kv_bytes, pipelined_finish
 from ..core.scheduler import DisaggDispatcher, FCFSQueue, least_loaded
+from ..core.telemetry import host_span, mark_gc
 from ..core.workload import Request
 from .api import (FINISH_FAILED, GREEDY, BackendBase, RequestState,
                   RequestStatus, ServedResult, sequence_tokens)
@@ -83,6 +84,7 @@ class _LiveBackend(BackendBase):
         self.seed = seed
         self._rng = np.random.default_rng(seed)
         self._init_backend(tracker=tracker, tracer=tracer, metrics=metrics)
+        mark_gc()
 
     def _reset_loop(self):
         """Fresh event loop, virtual clocks, and token rng (the legacy
@@ -376,13 +378,16 @@ class ServingCluster(_LiveBackend):
 
     def _handle(self, t: float, kind: str, payload: Any):
         if kind == "arrive":
-            self._on_arrive(payload, t)
+            with host_span("serve.route"):
+                self._on_arrive(payload, t)
         elif kind == "poke_prefill":
             self._poke_prefill(payload, t)
         elif kind == "dispatch_decode":
-            self._on_dispatch_decode(payload, t)
+            with host_span("serve.route"):
+                self._on_dispatch_decode(payload, t)
         elif kind == "predispatch_decode":
-            self._on_predispatch(payload, t)
+            with host_span("serve.route"):
+                self._on_predispatch(payload, t)
         elif kind == "finalize_stream":
             self._on_finalize_stream(payload, t)
         elif kind == "poke_decode":
@@ -685,9 +690,10 @@ class ServingCluster(_LiveBackend):
             or self._role("mixed")
         seq, req = state.seq, state.request
         e = E[least_loaded([x.load for x in E])]
-        wire = blob.owner.materialize_wire(blob, 0) \
-            if isinstance(blob, KVBlob) else blob
-        e.engine.insert_kv(seq, wire)
+        with host_span("serve.kv_handoff"):
+            wire = blob.owner.materialize_wire(blob, 0) \
+                if isinstance(blob, KVBlob) else blob
+            e.engine.insert_kv(seq, wire)
         self.tx.drop_partial(seq.rid)
         req.decode_admit = now
         req.transfer_done = now
@@ -739,16 +745,17 @@ class ServingCluster(_LiveBackend):
         parks."""
         seq, req = state.seq, state.request
         src = self.tx.parked[seq.rid].src
-        blob, t_first, t_full = self.tx.pull_streamed(seq.rid, now,
-                                                      dst=d.iid)
-        if isinstance(blob, KVBlob):
-            # page-backed blob: the prefill engine stitches the wire
-            # payload from its page pool (and drops its pins)
-            wire = blob.owner.materialize_wire(blob, skip)
-        else:
-            wire = _slice_blob(blob, skip)
-        d.engine.insert_kv(seq, wire, shared=pinned, skip_tokens=skip)
-        d.engine.unpin(pinned)
+        with host_span("serve.kv_handoff"):
+            blob, t_first, t_full = self.tx.pull_streamed(seq.rid, now,
+                                                          dst=d.iid)
+            if isinstance(blob, KVBlob):
+                # page-backed blob: the prefill engine stitches the wire
+                # payload from its page pool (and drops its pins)
+                wire = blob.owner.materialize_wire(blob, skip)
+            else:
+                wire = _slice_blob(blob, skip)
+            d.engine.insert_kv(seq, wire, shared=pinned, skip_tokens=skip)
+            d.engine.unpin(pinned)
         # per-layer streaming: decode starts attending once the first
         # layer of the last chunk lands, not at blob-complete; a granted
         # stream's wire may have finished during prefill (t_full < now),
@@ -809,7 +816,8 @@ class ServingCluster(_LiveBackend):
                     continue
                 self._admit_one(d, state, skip, pinned, now)
 
-        admit_ready()
+        with host_span("serve.admit"):
+            admit_ready()
         if pending and not d.active and not granted:
             # liveness fallback: nothing is running (so no future poke
             # will fire) and the head still can't admit — its eviction
@@ -820,7 +828,8 @@ class ServingCluster(_LiveBackend):
             for j, (state, _skip, pinned) in enumerate(pending):
                 e.unpin(pinned)
                 pending[j] = (state, 0, [])
-            admit_ready()
+            with host_span("serve.admit"):
+                admit_ready()
         # amortized marking: entries append at the tail, marked ones
         # accumulate at the front (see the simulator twin); streamed
         # entries stay PREFILLING-with-progress until their final chunk
@@ -877,14 +886,15 @@ class ServingCluster(_LiveBackend):
             self.tracer.complete("step", "decode_step", now, done_t,
                                  d.label, batch=len(batch), compute=dt)
         still = []
-        for seq in batch:
-            state = self._states[seq.rid]
-            self._emit_token(state, seq.tokens[-1], done_t)
-            if seq.done:
-                self._finish_state(state, done_t)
-                e.release(seq)
-            else:
-                still.append(seq)
+        with host_span("serve.decode.emit"):
+            for seq in batch:
+                state = self._states[seq.rid]
+                self._emit_token(state, seq.tokens[-1], done_t)
+                if seq.done:
+                    self._finish_state(state, done_t)
+                    e.release(seq)
+                else:
+                    still.append(seq)
         # late joiners append at the tail, as the simulator's `arrived`
         # entries extend `running`
         d.active = still + landing
@@ -1006,14 +1016,15 @@ class ServingCluster(_LiveBackend):
                                      x.label, batch=len(batch2),
                                      compute=dt)
             still = []
-            for seq in batch2:
-                state = self._states[seq.rid]
-                self._emit_token(state, seq.tokens[-1], done_t)
-                if seq.done:
-                    e.release(seq)
-                    self._finish_state(state, done_t)
-                else:
-                    still.append(seq)
+            with host_span("serve.decode.emit"):
+                for seq in batch2:
+                    state = self._states[seq.rid]
+                    self._emit_token(state, seq.tokens[-1], done_t)
+                    if seq.done:
+                        e.release(seq)
+                        self._finish_state(state, done_t)
+                    else:
+                        still.append(seq)
             x.active = still
             x.free_at = done_t
             self._ev.push(done_t, "poke", x)

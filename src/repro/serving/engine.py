@@ -15,7 +15,11 @@ the sequence use exact lengths instead.
 
 Step times are measured and accumulated on a virtual clock so a 1-CPU host
 can emulate N concurrent instances honestly (used by the Table-2
-simulator-accuracy experiment).
+simulator-accuracy experiment). Beside that clock, `stats()` counts on the
+host's clock the decode step's parts, the gaps between decode steps that
+live sequences sit through, and the wait from a sequence's creation to
+its first prefill; the host work itself is marked with
+`telemetry.host_span`s for the profiler.
 """
 from __future__ import annotations
 
@@ -27,6 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core.telemetry import host_span
 from ..models.api import build_model, supports_paged
 from .api import FINISH_LENGTH, FINISH_STOP, SamplingParams
 from .kv_cache import KVCacheManager, TRASH_PAGE
@@ -92,6 +97,9 @@ class Sequence:
     kv_full: float = 0.0        # when the last layer's KV lands (stream)
     sampling: Optional[SamplingParams] = None
     finish_reason: str = FINISH_LENGTH
+    # host clock (`perf_counter`) at creation, which is the request's submit
+    created: float = dataclasses.field(default_factory=time.perf_counter,
+                                       compare=False)
     _rng: Any = None            # lazy, only for temperature > 0
 
     def append_token(self, tok: int):
@@ -145,6 +153,12 @@ class Engine:
         self.decode_tokens = 0
         self.fused_dispatches = 0             # prefix_prefill kernel calls
         self.chunk_dispatches = 0             # chunked-prefill kernel calls
+        # host-clock seconds, cumulative (see `stats`)
+        self.dispatch_s = self.wait_s = self.pull_s = 0.0
+        self.held_gap_s = 0.0
+        self.queue_wait_s = 0.0
+        self.queue_waits = 0
+        self._held_since: Optional[float] = None
         if self.paged:
             pps = -(-max_len // page_size)
             # default pool: dense-slab-equivalent capacity + trash page 0
@@ -183,9 +197,22 @@ class Engine:
     def stats(self) -> Dict[str, float]:
         """Pull-collector snapshot for a `MetricsRegistry`: cumulative
         dispatch counters plus page-pool occupancy and prefix-tree state
-        when paged/prefix-caching."""
+        when paged/prefix-caching.
+
+        Host-clock seconds, cumulative: `dispatch_s`, `wait_s` and
+        `pull_s` split each decode step into enqueueing the step, waiting
+        for it (together what the step adds to `clock_s`) and taking its
+        tokens to the host; `held_gap_s` is the time from the end of one
+        decode step to the start of the next while the engine holds a
+        sequence that is not done; `queue_wait_s` over `queue_waits` is
+        the mean time from a sequence's creation to its first prefill
+        here."""
         out: Dict[str, float] = {
             "clock_s": self.clock, "steps": self.steps,
+            "dispatch_s": self.dispatch_s, "wait_s": self.wait_s,
+            "pull_s": self.pull_s, "held_gap_s": self.held_gap_s,
+            "queue_wait_s": self.queue_wait_s,
+            "queue_waits": self.queue_waits,
             "prefill_tokens": self.prefill_tokens,
             "prefix_hit_tokens": self.prefix_hit_tokens,
             "decode_tokens": self.decode_tokens,
@@ -466,6 +493,11 @@ class Engine:
         The new full prompt pages are inserted into the radix tree for
         later requests, and the blob handed to the transfer layer is
         stitched from shared + fresh pages."""
+        with host_span("serve.prefill"):
+            self._note_queue_wait(seq)
+            return self._prefill_request(seq)
+
+    def _prefill_request(self, seq: Sequence) -> Tuple[int, Any, float]:
         toks = np.asarray(seq.tokens, np.int32)
         S = len(toks)
         assert S < self.max_len, (S, self.max_len)
@@ -486,7 +518,7 @@ class Engine:
         self.clock += dt
         self.steps += 1
         self.prefill_tokens += S
-        first = self._sample_token(seq, logits[0, 0])
+        first = self._sample_first(seq, logits[0, 0])
         return first, (cache, S), dt
 
     def _prefill_with_prefix(self, seq: Sequence, toks) -> Tuple[int, Any, float]:
@@ -508,7 +540,7 @@ class Engine:
         fused = bool(hit and self.fused_prefix)
         logits, cache, prefix_kv = self._forward_chunk(
             padded, hit_pages, hit, Ssuf - 1, fused)
-        first = self._sample_token(seq, logits[0, 0])
+        first = self._sample_first(seq, logits[0, 0])
 
         # the migration blob: on the fused path it carries only the suffix
         # KV (the prefix stays pinned in the page pool until the transfer
@@ -601,6 +633,11 @@ class Engine:
         `first_token`/`blob` are None until the final chunk, where the
         blob is fully page-backed (`prefix_tokens == n_tok`, pages pinned
         until `materialize_wire`/`release_blob`)."""
+        with host_span("serve.prefill"):
+            self._note_queue_wait(seq)
+            return self._prefill_chunk(seq, chunk_tokens)
+
+    def _prefill_chunk(self, seq: Sequence, chunk_tokens: int):
         assert self.paged, "chunked prefill needs the paged runtime"
         toks = np.asarray(seq.tokens, np.int32)
         S = len(toks)
@@ -636,7 +673,7 @@ class Engine:
         fused = self.fused_prefix if self.prefix_caching else True
         logits, cache, _ = self._forward_chunk(
             padded, st.table[:ctx // ps], ctx, c - 1, fused)
-        first = self._sample_token(seq, logits[0, 0]) if final else None
+        first = self._sample_first(seq, logits[0, 0]) if final else None
         # in-place paged write of the chunk's fresh KV
         first_page = ctx // ps
         n_chunk_pages = -(-(ctx + c) // ps) - first_page
@@ -852,6 +889,8 @@ class Engine:
                 self._cache["pos"] = self._cache["pos"].at[seq.slot].set(0)
             self._slot_free.append(seq.slot)
             seq.slot = -1
+            if len(self._slot_free) == self.max_batch:
+                self._held_since = None     # nothing left to stall
 
     def cancel(self, seq: Sequence, pinned: List[int] = ()):
         """Abort a sequence at any lifecycle stage without leaking: drop
@@ -864,6 +903,16 @@ class Engine:
         if self.paged:
             self.abort_partial(seq)
         self.release(seq)
+
+    def _note_queue_wait(self, seq: Sequence):
+        """Count the wait of a sequence whose first prefill starts now."""
+        if not seq.produced and not seq.prefilled:
+            self.queue_wait_s += time.perf_counter() - seq.created
+            self.queue_waits += 1
+
+    def _sample_first(self, seq: Sequence, logits_row) -> int:
+        with host_span("serve.prefill.sample"):
+            return self._sample_token(seq, logits_row)
 
     def _sample_token(self, seq: Sequence, logits_row) -> int:
         """Greedy argmax (default) or temperature softmax sampling with
@@ -881,25 +930,37 @@ class Engine:
         """One decode iteration for all active sequences."""
         if not seqs:
             return 0.0
-        tokens = np.zeros((self.max_batch,), np.int32)
-        for s in seqs:
-            tokens[s.slot] = s.tokens[-1]
-        t0 = time.perf_counter()
-        logits, self._cache = self._decode_fn(self.params, self._cache,
-                                              jnp.asarray(tokens))
-        logits.block_until_ready()
-        dt = time.perf_counter() - t0
+        with host_span("serve.decode.dispatch"):
+            tokens = np.zeros((self.max_batch,), np.int32)
+            for s in seqs:
+                tokens[s.slot] = s.tokens[-1]
+            t0 = time.perf_counter()
+            logits, self._cache = self._decode_fn(self.params, self._cache,
+                                                  jnp.asarray(tokens))
+            t1 = time.perf_counter()
+        with host_span("serve.decode.wait"):
+            logits.block_until_ready()
+            t2 = time.perf_counter()
+        with host_span("serve.decode.pull"):
+            nxt = np.asarray(jnp.argmax(logits, axis=-1))
+            sampled = any(s.sampling is not None
+                          and s.sampling.temperature > 0 for s in seqs)
+            rows = np.asarray(logits) if sampled else None
+            for s in seqs:
+                if s.sampling is not None and s.sampling.temperature > 0:
+                    tok = self._sample_token(s, rows[s.slot])
+                else:
+                    tok = int(nxt[s.slot])
+                s.append_token(tok)
+            t3 = time.perf_counter()
+        if self._held_since is not None:
+            self.held_gap_s += t0 - self._held_since
+        self._held_since = t3 if any(not s.done for s in seqs) else None
+        dt = t2 - t0
         self.clock += dt
         self.steps += 1
         self.decode_tokens += len(seqs)
-        nxt = np.asarray(jnp.argmax(logits, axis=-1))
-        sampled = any(s.sampling is not None and s.sampling.temperature > 0
-                      for s in seqs)
-        rows = np.asarray(logits) if sampled else None
-        for s in seqs:
-            if s.sampling is not None and s.sampling.temperature > 0:
-                tok = self._sample_token(s, rows[s.slot])
-            else:
-                tok = int(nxt[s.slot])
-            s.append_token(tok)
+        self.dispatch_s += t1 - t0
+        self.wait_s += t2 - t1
+        self.pull_s += t3 - t2
         return dt

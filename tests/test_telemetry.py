@@ -125,34 +125,6 @@ def test_validator_rejects_corrupt_traces():
     assert validate_chrome_trace({"not": "a trace"})
 
 
-def test_wall_clock_stamps_opt_in_and_validate():
-    """`Tracer(wall_clock=...)` stamps spans/instants with wall marks;
-    virtual time stays the span identity and the exporter schema-checks
-    the marks (wall_t1 >= wall_t0, numeric)."""
-    ticks = iter([10.0, 10.25, 10.5, 11.0])
-    tr = Tracer(wall_clock=lambda: next(ticks))
-    tr.complete("compute", "chunk", 0.0, 1.0, "prefill0", rid=1)
-    tr.event("token", 0.5, rid=1, i=0)
-    sp = tr.spans[0]
-    assert (sp.wall_t0, sp.wall_t1) == (10.0, 10.25)
-    assert sp.t0 == 0.0 and sp.t1 == 1.0          # virtual clock untouched
-    assert tr.instants[0].wall_t == 10.5
-    doc = json.loads(json.dumps(to_chrome_trace(tr)))
-    assert validate_chrome_trace(doc) == []
-    x = next(e for e in doc["traceEvents"] if e["ph"] == "X")
-    assert x["args"]["wall_t0"] == 10.0 and x["args"]["wall_t1"] == 10.25
-    # a regressive wall interval is a schema error
-    bad = json.loads(json.dumps(doc))
-    xi = next(i for i, e in enumerate(bad["traceEvents"])
-              if e["ph"] == "X")
-    bad["traceEvents"][xi]["args"]["wall_t1"] = 9.0
-    assert validate_chrome_trace(bad)
-    # without the hook nothing is stamped
-    off = Tracer()
-    off.complete("compute", "chunk", 0.0, 1.0, "prefill0", rid=1)
-    assert off.spans[0].wall_t0 is None and off.spans[0].wall_t1 is None
-
-
 # ---------------- metrics registry -----------------------------------------
 
 def test_metrics_registry_snapshot_and_prometheus():
