@@ -13,17 +13,17 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@partial(jax.jit, static_argnames=("scale", "softcap", "impl", "dbuf"))
+@partial(jax.jit, static_argnames=("scale", "softcap", "impl"))
 def paged_decode_op(q, k_pages, v_pages, block_table, lens, *,
                     scale: float = None, softcap: float = 0.0,
-                    impl: str = "auto", dbuf: bool = False):
+                    impl: str = "auto"):
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "ref"
     if impl == "ref":
         return paged_decode_ref(q, k_pages, v_pages, block_table, lens,
                                 scale=scale, softcap=softcap)
     return paged_decode(q, k_pages, v_pages, block_table, lens,
-                        scale=scale, softcap=softcap, dbuf=dbuf,
+                        scale=scale, softcap=softcap,
                         interpret=(impl == "interpret"))
 
 

@@ -30,6 +30,8 @@ def paged_decode_ref(q, k_pages, v_pages, block_table, lens, *,
     s = jnp.where(mask[:, None, :], s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("bhs,bshd->bhd", p, vr.astype(jnp.float32))
+    # a slot of length 0 attends to nothing
+    o = jnp.where((lens > 0)[:, None, None], o, 0.0)
     return o.astype(q.dtype)
 
 

@@ -419,7 +419,10 @@ def decode_step_paged(params, cache, tokens, cfg):
         wpos = jnp.minimum(pos, max_pps * page_size - 1)
         pidx = table[bidx, wpos // page_size]
         off = wpos % page_size
-        lens = jnp.minimum(pos + 1, max_pps * page_size)
+        # a free slot's table starts at the trash page: it attends to
+        # nothing, so the page walk skips it
+        lens = jnp.where(table[:, 0] == 0, 0,
+                         jnp.minimum(pos + 1, max_pps * page_size))
 
         def body(x, layer, _kind=seg.kind):
             pl, kc_l, vc_l = layer

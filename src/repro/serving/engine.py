@@ -158,6 +158,9 @@ class Engine:
         self.held_gap_s = 0.0
         self.queue_wait_s = 0.0
         self.queue_waits = 0
+        # pages the decode kernel walks per layer, and what a walk of every
+        # slot's whole table would be (see `stats`)
+        self.pages_walked = self.pages_table = 0
         self._held_since: Optional[float] = None
         if self.paged:
             pps = -(-max_len // page_size)
@@ -206,7 +209,13 @@ class Engine:
         decode step to the start of the next while the engine holds a
         sequence that is not done; `queue_wait_s` over `queue_waits` is
         the mean time from a sequence's creation to its first prefill
-        here."""
+        here.
+
+        Paged engines also count, per layer and summed over decode steps,
+        `pages_walked`: the pages the decode kernel walks, each live
+        slot's `ceil((pos + 1) / page_size)`; and `pages_table`: the
+        `max_batch * pages_per_seq` a walk of every table entry would
+        take."""
         out: Dict[str, float] = {
             "clock_s": self.clock, "steps": self.steps,
             "dispatch_s": self.dispatch_s, "wait_s": self.wait_s,
@@ -223,6 +232,8 @@ class Engine:
         }
         if self._kv is not None:
             out.update(self._kv.stats())
+            out["pages_walked"] = self.pages_walked
+            out["pages_table"] = self.pages_table
         if self.prefix_caching:
             for k, v in self.prefix_cache.metrics().items():
                 out[f"prefix.{k}"] = v
@@ -934,6 +945,11 @@ class Engine:
             tokens = np.zeros((self.max_batch,), np.int32)
             for s in seqs:
                 tokens[s.slot] = s.tokens[-1]
+            if self.paged:
+                ps, pps = self._kv.page_size, self._kv.max_pages_per_seq
+                self.pages_walked += sum(min(-(-len(s.tokens) // ps), pps)
+                                         for s in seqs)
+                self.pages_table += self.max_batch * pps
             t0 = time.perf_counter()
             logits, self._cache = self._decode_fn(self.params, self._cache,
                                                   jnp.asarray(tokens))

@@ -125,8 +125,10 @@ def test_counters_reach_the_metrics_registry(params):
     assert snap["decode0.dispatch_s"] > 0 and snap["decode0.pull_s"] > 0
     assert snap["prefill0.queue_waits"] == 2
     text = reg.prometheus()
-    for key in ("held_gap_s", "wait_s", "queue_wait_s"):
+    for key in ("held_gap_s", "wait_s", "queue_wait_s", "pages_walked",
+                "pages_table"):
         assert f"repro_decode0_{key} " in text
+    assert 0 < snap["decode0.pages_walked"] < snap["decode0.pages_table"]
 
 
 def test_clusters_mark_collections_once(params):

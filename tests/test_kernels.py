@@ -6,7 +6,8 @@ import pytest
 
 from repro.kernels.flash_prefill.kernel import flash_prefill
 from repro.kernels.flash_prefill.ref import flash_prefill_ref
-from repro.kernels.paged_decode.kernel import paged_decode, paged_insert
+from repro.kernels.paged_decode.kernel import (pages_per_block, paged_decode,
+                                               paged_insert)
 from repro.kernels.paged_decode.ref import paged_decode_ref, paged_insert_ref
 from repro.kernels.prefix_prefill.kernel import prefix_prefill
 from repro.kernels.prefix_prefill.ref import prefix_prefill_ref
@@ -150,29 +151,54 @@ def test_prefix_prefill_matches_flash_with_dense_prefix():
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-@pytest.mark.parametrize("shape", [
-    # (B, H, Hkv, hd, num_pages, page, pages_per_seq)
-    (2, 8, 2, 64, 16, 16, 4),
-    (3, 4, 4, 128, 32, 8, 8),
-])
-def test_paged_decode_dbuf_parity(shape, dtype):
-    """Async-copy double-buffered page walk == the BlockSpec-pipelined
-    kernel's oracle, pools in compiler-chosen memory, ragged lens."""
-    B, H, Hkv, hd, pages, page, pps = shape
-    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+@pytest.mark.parametrize("H,Hkv", [(32, 2), (32, 4)], ids=["G16", "G8"])
+def test_paged_decode_walks_only_live_pages(H, Hkv, dtype):
+    """The block walk == the oracle on clean pools, at chatglm3's (Hkv 2,
+    G 16) and yi-6b's (Hkv 4, G 8) ratios, for ragged lens: 1, a page
+    less one, exactly one block, one and a half blocks, the full table,
+    and 0, whose row is zero. Every position past a slot's length, the
+    rest of its reservation and the trash page 0 hold NaN: nothing there
+    is read into the result."""
+    hd, page = 128, 16
+    P = pages_per_block(page, Hkv, hd, jnp.dtype(dtype).itemsize, 10 ** 6)
+    pps = 2 * P + P // 2 + 1
+    lens = np.array([1, page - 1, P * page, P * page + P * page // 2 + 3,
+                     pps * page, 0], np.int32)
+    B = len(lens)
+    ks = jax.random.split(jax.random.PRNGKey(7), 3)
     q = jax.random.normal(ks[0], (B, H, hd), jnp.float32).astype(dtype)
+    pages = 1 + B * pps
     kp = jax.random.normal(ks[1], (pages, page, Hkv, hd),
                            jnp.float32).astype(dtype)
     vp = jax.random.normal(ks[2], (pages, page, Hkv, hd),
                            jnp.float32).astype(dtype)
-    table = jax.random.permutation(ks[0], pages)[:B * pps].reshape(B, pps)
-    table = table.astype(jnp.int32)
-    lens = jnp.array([1 + (11 * i + 7) % (pps * page) for i in range(B)],
-                     jnp.int32)
+    table = 1 + np.arange(B * pps, dtype=np.int32).reshape(B, pps)
+    table[1, 1:] = 0                    # a reservation ending in trash
+    table[5] = 0                        # a free slot
+    live = np.zeros((pages, page), bool)
+    for b in range(B):
+        for t in range(lens[b]):
+            live[table[b, t // page], t % page] = True
+    dirty = jnp.asarray(live)[:, :, None, None]
+    kn = jnp.where(dirty, kp, jnp.nan)
+    vn = jnp.where(dirty, vp, jnp.nan)
+    table, lens = jnp.asarray(table), jnp.asarray(lens)
     ref = paged_decode_ref(q, kp, vp, table, lens)
-    out = paged_decode(q, kp, vp, table, lens, dbuf=True, interpret=True)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32), **TOLS[dtype])
+    out = np.asarray(paged_decode(q, kn, vn, table, lens, interpret=True),
+                     np.float32)
+    assert np.isfinite(out).all()
+    assert (out[5] == 0).all()
+    np.testing.assert_allclose(out, np.asarray(ref, np.float32),
+                               **TOLS[dtype])
+
+
+def test_paged_decode_with_no_live_slot():
+    """A batch of free slots walks nothing and returns zeros."""
+    q = jnp.ones((3, 8, 128))
+    pool = jnp.full((4, 16, 2, 128), jnp.nan)
+    out = paged_decode(q, pool, pool, jnp.zeros((3, 4), jnp.int32),
+                       jnp.zeros((3,), jnp.int32), interpret=True)
+    np.testing.assert_array_equal(np.asarray(out), 0.0)
 
 
 def test_prefix_prefill_dbuf_parity():

@@ -105,6 +105,67 @@ def test_insert_kv_is_block_table_splice(params):
     assert (rowB[len(tabB):] == TRASH_PAGE).all()
 
 
+# ---------------- the decode kernel's page walk ---------------------------
+
+def _decode_run(params, release_at=None, steps=6):
+    """Three residents decoded together; with `release_at`, the middle one
+    is released after that many steps. Returns the engine, the sequences,
+    and for each step the `pages_walked` added and the lens the step hands
+    `paged_decode_op`."""
+    from repro.kernels.paged_decode import ops
+    eng = Engine(CFG, params, max_batch=3, max_len=64, page_size=16)
+    rng = np.random.default_rng(3)
+    seqs = [Sequence(i, rng.integers(1, CFG.vocab_size, n).tolist(), 40)
+            for i, n in enumerate((9, 16, 31))]
+    for s in seqs:
+        _prefill_into(eng, s)
+    seen = []
+    orig = ops.paged_decode_op
+
+    def spy(q, k, v, table, lens, **kw):
+        jax.debug.callback(lambda x: seen.append(np.asarray(x)), lens)
+        return orig(q, k, v, table, lens, **kw)
+
+    walked = []
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "paged_decode_op", spy)
+        live = list(seqs)
+        for step in range(steps):
+            if step == release_at:
+                eng.release(seqs[1])
+                live.remove(seqs[1])
+            before = eng.stats()["pages_walked"]
+            seen.clear()
+            eng.decode_step(live)
+            walked.append((eng.stats()["pages_walked"] - before, seen[0]))
+    return eng, seqs, walked
+
+
+def test_pages_walked_is_the_kernels_walk(params):
+    """`pages_walked` adds, per decode step, each live slot's
+    `ceil((pos + 1) / page_size)`: the pages the kernel walks for the lens
+    the step hands it. A released slot hands it 0, so it walks nothing;
+    `pages_table` is the whole table per step."""
+    eng, seqs, walked = _decode_run(params, release_at=3)
+    ps = 16
+    for step, (added, lens) in enumerate(walked):
+        assert added == sum(-(-int(n) // ps) for n in lens), step
+    assert walked[2][1][1] > 0 and walked[3][1][1] == 0   # the middle slot
+    # the third slot crosses a page boundary: 32 -> 33 positions
+    assert walked[0][0] == 1 + 2 + 2 and walked[1][0] == 1 + 2 + 3
+    st = eng.stats()
+    assert st["pages_table"] == len(walked) * 3 * 4
+
+
+def test_release_mid_batch_leaves_batchmates_tokens(params):
+    """Releasing the middle slot of a batch changes no other slot's
+    decoded tokens."""
+    _, kept, _ = _decode_run(params)
+    _, cut, _ = _decode_run(params, release_at=2)
+    for i in (0, 2):
+        assert cut[i].tokens == kept[i].tokens
+
+
 # ---------------- paged == dense tokens -----------------------------------
 
 def _reqs(n=6):
