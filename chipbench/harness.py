@@ -25,8 +25,8 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from . import reference, spec, trace as tracing, traffic
-from .model import Dims, dims, make_params
+from . import archs, reference, spec, trace as tracing, traffic
+from .model import dims, make_params
 
 
 def percentile(xs, q: float) -> float:
@@ -156,10 +156,15 @@ class Client:
         return None if h is None else h.status.name
 
     def backlog(self, t: float) -> int:
-        """Requests due by host time `t` without a first token by then."""
+        """Requests due by host time `t` that had not reached decode by
+        then: no second token (the first comes from prefill), so a queue
+        before prefill and one before decode both count."""
+        def decoding(r):
+            st = self.stamps.get(r.idx, ())
+            k = min(2, r.out_len)
+            return len(st) >= k and st[k - 1] <= t
         return sum(1 for r in self.reqs if self.origin + r.due <= t
-                   and not (self.stamps.get(r.idx)
-                            and self.stamps[r.idx][0] <= t))
+                   and not decoding(r))
 
     def cancel_rest(self):
         """Cancel what is still in flight, so that the cluster can serve
@@ -200,18 +205,10 @@ def _annotate():
 # set-up
 # ---------------------------------------------------------------------------
 
-def check_program(m: Dims, pcfg) -> None:
-    """The program's architecture entry must have the file's widths."""
-    want = dict(num_layers=m.layers, d_model=m.d, num_heads=m.heads,
-                num_kv_heads=m.kv_heads, head_dim=m.hd, d_ff=m.ff,
-                vocab_size=m.vocab, rope_theta=m.rope_theta,
-                rope_fraction=m.rope_fraction,
-                rope_interleaved=m.rope_interleaved, norm_eps=m.eps)
-    bad = {k: (getattr(pcfg, k), v) for k, v in want.items()
-           if getattr(pcfg, k) != v}
-    if bad or pcfg.family != "dense" or pcfg.norm_type != "rmsnorm":
-        raise SystemExit(f"program config {pcfg.name} differs from the "
-                         f"benchmark's configuration: {bad}")
+def check_program(m: archs.Dims, pcfg) -> None:
+    """The program's architecture entry must have the file's widths and
+    family; `SystemExit` where it has not."""
+    archs.of(m).check_program(m, pcfg)
 
 
 def warm_shapes(cluster, page: int, mix: dict, vocab: int, rng
@@ -225,6 +222,7 @@ def warm_shapes(cluster, page: int, mix: dict, vocab: int, rng
     each prompt page count in the range (the program compiles one splice
     per page count and bucket), then releases it. Decode: two short
     requests run through the whole cluster."""
+    import jax
     from repro.core.workload import Request
     from repro.serving.engine import Sequence
     pe, de = cluster.prefill[0], cluster.decode[0]
@@ -243,7 +241,7 @@ def warm_shapes(cluster, page: int, mix: dict, vocab: int, rng
             seq = Sequence(rid[0], rng.integers(1, vocab, S).tolist(), 2)
             _first, blob, _dt = pe.prefill_request(seq)
             src = next(v for k, v in blob[0].items() if k.startswith("seg"))
-            probes[pages] = src["k"].shape[2]
+            probes[pages] = jax.tree.leaves(src)[0].shape[2]
             caches.setdefault(probes[pages], blob[0])
         return probes[pages]
 
@@ -288,7 +286,7 @@ class Run:
     """What a metric reader gets: the model's dimensions, the chip's peaks,
     the window's counters and steps, and the trace summary (None without
     `--trace 1`)."""
-    dims: Dims
+    dims: archs.Dims
     peak: dict
     counters: Dict[str, float]
     steps: List[StepRecord]
@@ -459,8 +457,8 @@ class Session:
         log(f"window {window_len:.3f} s, {len(win)} requests due, "
             f"{len(tpots)} with a first token, {sum(r['ok'] for r in rows)} "
             f"within both limits; {out_tokens} tokens in the window; "
-            f"followed {stop_t - t_close:.3f} s after it; backlog (due, no "
-            f"first token) {backlog['open']} at open, {backlog['close']} at "
+            f"followed {stop_t - t_close:.3f} s after it; backlog (due, not "
+            f"decoding) {backlog['open']} at open, {backlog['close']} at "
             f"close")
         log(f"generator lateness p50 {percentile(late, 0.5) * 1e3:.3f} ms, "
             f"p99 {percentile(late, 0.99) * 1e3:.3f} ms")
@@ -594,8 +592,9 @@ def verdict(gap: float, n: int, check_cfg: dict) -> dict:
                        "tokens_compared_min": {"value": n, "limit": need}}}
 
 
-def check_served(m: Dims, params, served: Dict[int, tuple], check_cfg: dict,
-                 seed: int, log=print, control: bool = False) -> dict:
+def check_served(m: archs.Dims, params, served: Dict[int, tuple],
+                 check_cfg: dict, seed: int, log=print,
+                 control: bool = False) -> dict:
     """Compare the served tokens of a sample with the reference: the
     widest gap by which a served token's reference logit lies below the
     reference's best, against the configuration's limit. With `control`,

@@ -3,8 +3,10 @@
 `BENCHMARK.json` names the cells; each cell names a configuration
 (`chipbench/configs/<name>.json`, through the `file` of its entry) and a
 traffic mix (`chipbench/traffic/<name>.json`). A per-layer metric is read
-by `chipbench/metrics/<name>.py`. Nothing here is specific to one cell, so
-a later cell, mix, configuration or metric is a new file and a new entry.
+by `chipbench/metrics/<name>.py`, and a configuration's architecture is
+`chipbench/archs/<model_type>.py` (`archs.load`). Nothing here is specific
+to one cell, so a later cell, mix, configuration, architecture or metric
+is a new file and a new entry.
 """
 from __future__ import annotations
 

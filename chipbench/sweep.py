@@ -5,11 +5,13 @@ another on one cluster, and report what each rate did.
     python3 chipbench/sweep.py --workload <cell> --rates 0.5,1,1.5 \
         --seconds 30 [--seed 1] [--out sweep.json]
 
-The knee is the highest rate at which 90% of the requests due in the
-window meet both limits of the mix with no growing backlog (requests due
-without a first token, at the window's open and at its close). The rate a
-cell runs at is then written into its traffic file as a number. Prints one
-JSON line per rate.
+The knee is the highest rate with no growing backlog: requests due that
+have not reached decode (no second token), at the window's open and at
+its close. Past it a queue grows, before prefill or, with the first token
+already out, before decode. Attainment of the mix's limits is reported
+beside it; where the chip cannot meet them for 90% of requests at any
+rate, the backlog alone decides. The rate a cell runs at is then written
+into its traffic file as a number. Prints one JSON line per rate.
 """
 from __future__ import annotations
 
